@@ -250,6 +250,9 @@ def run(n_requests: int = 120, kills: int = 2, seed: int = 0):
 
 if __name__ == "__main__":
     import sys
+
+    from repro import compile_cache
+    compile_cache.enable()
     if "--chaos-only" in sys.argv:     # CI chaos-soak job: scenario 2
         rows, report = _survivable_streams()
         _merge_report(report)

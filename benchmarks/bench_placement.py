@@ -152,5 +152,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     for name, us, derived in run():
         print(f"{name:36s} {us:12.1f} us/call   {derived}")

@@ -699,5 +699,7 @@ def run(n_requests: int = 12, max_tokens: int = 24,
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     for name, us, derived in run():
         print(f"{name},{us:.2f},{derived}")

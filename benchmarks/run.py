@@ -18,6 +18,8 @@ import traceback
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_availability, bench_placement, bench_lb,
                             bench_serving, bench_kernels,
                             bench_compression, bench_roofline)

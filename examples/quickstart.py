@@ -13,6 +13,7 @@ import dataclasses
 
 import jax
 
+from repro import compile_cache
 from repro.api import Gateway
 from repro.api.http import GatewayHTTPServer, HTTPClient, HTTPConfig
 from repro.cluster import paper_testbed
@@ -34,6 +35,7 @@ def param_store(cfg):
 
 
 def main():
+    compile_cache.enable()
     fleet = paper_testbed(param_store=param_store)
     catalog = ModelCatalog()
     llama = dataclasses.replace(ZOO["llama3.2-1b"].reduced(),

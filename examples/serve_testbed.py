@@ -17,6 +17,7 @@ import random
 
 import jax
 
+from repro import compile_cache
 from repro.api import Gateway
 from repro.cluster import paper_testbed
 from repro.configs import ZOO
@@ -35,6 +36,7 @@ def param_store(cfg):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=60)
     ap.add_argument("--seed", type=int, default=0)

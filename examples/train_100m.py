@@ -11,6 +11,7 @@ atomic checkpoints, crash-resume).
 """
 import argparse
 
+from repro import compile_cache
 from repro.configs import ARCHS
 from repro.training.data import DataConfig
 from repro.training.optimizer import AdamWConfig
@@ -18,6 +19,7 @@ from repro.training.train_loop import TrainConfig, Trainer
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--tiny", action="store_true")

@@ -6,6 +6,7 @@ overview, and the rendered HAProxy-style frontend config.
 """
 import json
 
+from repro import compile_cache
 from repro.cluster import paper_testbed
 from repro.configs import ZOO
 from repro.core import (ConfigWizard, ControllerConfig, ModelCatalog,
@@ -14,6 +15,7 @@ from repro.core import (ConfigWizard, ControllerConfig, ModelCatalog,
 
 
 def main():
+    compile_cache.enable()
     fleet = paper_testbed()
     catalog = ModelCatalog()
     for name in ("deepseek-r1-7b", "qwen3-8b", "llama3.2-1b",

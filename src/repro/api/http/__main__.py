@@ -14,6 +14,7 @@ import time
 
 import jax
 
+from repro import compile_cache
 from repro.api import Gateway
 from repro.api.http.server import GatewayHTTPServer, HTTPConfig
 from repro.cluster import paper_testbed
@@ -40,6 +41,7 @@ def main(argv=None) -> int:
                         "are deployed so the demo runs on CPU)")
     p.add_argument("--replicas", type=int, default=2)
     args = p.parse_args(argv)
+    compile_cache.enable()
 
     fleet = paper_testbed(param_store=_param_store)
     catalog = ModelCatalog()

@@ -24,10 +24,12 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+import traceback
 from typing import TYPE_CHECKING, Dict, List, Optional
 
 from repro.core.controller import ModelLoad
-from repro.core.events import NODE_SUSPECTED, WATCHDOG_FIRED
+from repro.core.events import (NODE_SUSPECTED, PUMP_ERROR, TICK_ERROR,
+                               WATCHDOG_FIRED)
 
 if TYPE_CHECKING:                      # avoid import cycle at runtime
     from repro.api.gateway import Gateway
@@ -85,6 +87,16 @@ class _NodePump(threading.Thread):
             self.busy_since = time.monotonic()
             try:
                 emitted = node.pump()
+            except Exception as e:
+                # engine step failures are handled inside pump(); what
+                # reaches here is the node's own machinery.  Record it
+                # and keep serving: a dead pump thread would leave every
+                # request on this node to time out
+                rt.gateway.c.bus.emit(PUMP_ERROR, node=node.node_id,
+                                      error=repr(e),
+                                      traceback=traceback.format_exc())
+                emitted = 0
+                time.sleep(rt.cfg.pump_idle_wait_s)
             finally:
                 self.busy_since = None
             with rt._stats_lock:       # N pump threads share these
@@ -105,7 +117,7 @@ class _TickLoop(threading.Thread):
             try:
                 rt.tick_once()
             except Exception as e:     # keep the loop alive; surface it
-                rt.gateway.c.bus.emit("tick_error", error=repr(e))
+                rt.gateway.c.bus.emit(TICK_ERROR, error=repr(e))
 
 
 class ServingRuntime:

@@ -1,11 +1,13 @@
 """Backend node agent — one heterogeneous Service-Backend node.
 
 A node hosts multiple model *instances* (engines) packed into its HBM by the
-SDAI controller.  Small models run REAL jitted engines; large configs run in
-`accounted` mode (exact byte accounting + analytic latency from the node's
-capability vector) so thousand-node fleets stay simulable on one host.  Each
-node also runs its own replica proxy (`NodeProxy` in core/frontend.py),
-mirroring the paper's per-node HAProxy.
+SDAI controller.  Instances deployed with `real=True` run jitted engines on
+the node's one device; `real=False` deploys run in `accounted` mode (exact
+byte accounting, synthetic tokens) so thousand-node fleets stay simulable
+on one host.  A real deploy that cannot build its engine raises
+`DeployError`: it never degrades to accounted mode.  Each node also runs
+its own replica proxy (`NodeProxy` in core/frontend.py), mirroring the
+paper's per-node HAProxy.
 """
 from __future__ import annotations
 
@@ -14,8 +16,11 @@ import functools
 import itertools
 import threading
 import time
+import traceback
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, Optional
+
+import jax
 
 from repro.cluster.hardware import (NODE_CLASSES, RUNTIME_RESERVE_FRACTION,
                                     NodeClass)
@@ -24,6 +29,10 @@ from repro.serving.engine import EngineConfig, EngineFailure, InferenceEngine
 from repro.serving.request import CODE_ENGINE_FAILED, Request
 
 _inst_ids = itertools.count()
+
+
+class DeployError(RuntimeError):
+    """A real engine was asked for and could not be built."""
 
 
 def kv_pool_bytes(cfg: ArchConfig, n_slots: int, max_len: int,
@@ -88,8 +97,12 @@ class Instance:
 
 class BackendNode:
     def __init__(self, node_id: str, klass: str,
-                 param_store=None, seed: int = 0):
+                 param_store=None, seed: int = 0,
+                 device: Optional[jax.Device] = None):
         self.node_id = node_id
+        # the one device this node's engines live on (None => the first
+        # device, resolved at the first real deploy)
+        self.device = device
         self.klass: NodeClass = NODE_CLASSES[klass]
         self.instances: Dict[int, Instance] = {}
         self.param_store = param_store          # model name -> params fn
@@ -100,6 +113,9 @@ class BackendNode:
         # in production.  Consulted at the pump/submit/heartbeat
         # boundaries so faults land at exact, reproducible step counts.
         self.faults = None
+        # set by the registering controller: called with (instance,
+        # reason, traceback) when an engine step raises inside pump()
+        self.on_engine_failed = None
         # `lock` guards node structure (the instances map, alive flag);
         # engine mutation is serialized per-instance on `Instance.lock`
         # (always acquired *after* the node lock, never before — no
@@ -187,7 +203,9 @@ class BackendNode:
         analogue).  `kv_pages` sizes the paged KV pool (0 => the
         contiguous-equivalent budget); HBM is charged by page budget, not
         worst-case strips.  Raises MemoryError when it would not fit —
-        placement should never let that happen (property-tested)."""
+        placement should never let that happen (property-tested) — and
+        DeployError when `real` is asked for but the param store has no
+        weights for `cfg`."""
         pages_per_slot = -(-max_len // page_size)
         eff_pages = kv_pages if (paged and cfg.block != "xlstm") \
             and kv_pages else n_slots * pages_per_slot
@@ -201,21 +219,25 @@ class BackendNode:
         if real:
             params = self.param_store(cfg) if self.param_store else None
             if params is None:
-                real = False
-            else:
-                engine = InferenceEngine(
-                    cfg, params,
-                    EngineConfig(n_slots=n_slots, max_len=max_len,
-                                 quantize=quantize, seed=self._seed,
-                                 decode_block=decode_block,
-                                 page_size=page_size, kv_pages=kv_pages,
-                                 paged=paged, prefix_cache=prefix_cache,
-                                 prefix_cache_pages=prefix_cache_pages,
-                                 host_kv_pages=host_kv_pages,
-                                 prefix_share_tenants=prefix_share_tenants,
-                                 paged_attention=paged_attention,
-                                 speculative=speculative,
-                                 spec_draft=spec_draft))
+                raise DeployError(
+                    f"{self.node_id}: no params for {cfg.name}; a real "
+                    f"engine was asked for")
+            if self.device is None:
+                self.device = jax.devices()[0]
+            engine = InferenceEngine(
+                cfg, params,
+                EngineConfig(n_slots=n_slots, max_len=max_len,
+                             quantize=quantize, seed=self._seed,
+                             decode_block=decode_block,
+                             page_size=page_size, kv_pages=kv_pages,
+                             paged=paged, prefix_cache=prefix_cache,
+                             prefix_cache_pages=prefix_cache_pages,
+                             host_kv_pages=host_kv_pages,
+                             prefix_share_tenants=prefix_share_tenants,
+                             paged_attention=paged_attention,
+                             speculative=speculative,
+                             spec_draft=spec_draft),
+                device=self.device)
         inst = Instance(next(_inst_ids), cfg.name, cfg, quantize, n_slots,
                         max_len, need, engine, page_size=page_size,
                         kv_pages=eff_pages)
@@ -309,7 +331,20 @@ class BackendNode:
                         emitted += eng.step()
                     except EngineFailure:
                         break            # failed under us mid-loop
+                    except Exception as e:
+                        # a step that raises (compile error, device
+                        # fault) fails this engine: its requests finish
+                        # ENGINE_FAILED (and migrate where a replica
+                        # survives) instead of waiting out a timeout
+                        self._engine_failed(inst, e)
+                        break
         return emitted
+
+    def _engine_failed(self, inst: Instance, exc: Exception):
+        reason = f"{type(exc).__name__}: {exc}"
+        inst.engine.fail(reason=reason)
+        if self.on_engine_failed is not None:
+            self.on_engine_failed(inst, reason, traceback.format_exc())
 
     def _get_executor(self, n: int) -> ThreadPoolExecutor:
         # under the node lock: recover() tears the pool down concurrently

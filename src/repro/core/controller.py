@@ -16,7 +16,8 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.cluster.fleet import Fleet
-from repro.core.events import EventBus
+from repro.cluster.node import DeployError
+from repro.core.events import ENGINE_FAILED, EventBus
 from repro.core.frontend import FrontendConfig, ServiceFrontend
 from repro.core.health import HealthConfig, HealthMonitor, NodeHealth
 from repro.core.perfmodel import PerfModel
@@ -121,12 +122,25 @@ class SDAIController:
         for node in self.fleet.nodes.values():
             if not node.alive:
                 continue
-            payload = node.discovery_payload()
-            self.nodes.register(payload)
+            payload = self._register(node)
             self.monitor.observe_heartbeat(node.node_id)
             self.bus.emit("node_discovered", **payload)
             found.append(node.node_id)
         return found
+
+    def _register(self, node) -> dict:
+        """Record a node's capability payload and route its engine
+        failures onto the event bus.  Returns the payload."""
+        payload = node.discovery_payload()
+        self.nodes.register(payload)
+
+        def on_engine_failed(inst, reason, tb, nid=node.node_id):
+            self.bus.emit(ENGINE_FAILED, node=nid,
+                          instance=inst.instance_id,
+                          model=inst.model_name, error=reason,
+                          traceback=tb)
+        node.on_engine_failed = on_engine_failed
+        return payload
 
     # ---------------------------------------------------------------- #
     def _free_capacity(self) -> Dict[str, tuple]:
@@ -161,7 +175,9 @@ class SDAIController:
                                    n_slots=a.n_slots, max_len=a.max_len,
                                    real=real, page_size=a.page_size,
                                    kv_pages=a.kv_pages)
-            except MemoryError as e:      # placement invariant violated
+            except (MemoryError, DeployError) as e:
+                # placement invariant violated, or no weights for a real
+                # engine: reported, never silently simulated
                 self.bus.emit("deploy_failed", node=a.node_id,
                               model=a.model_name, error=str(e))
                 continue
@@ -176,7 +192,7 @@ class SDAIController:
                                           a.n_slots, a.max_len, a.bytes))
             self.bus.emit("instance_deployed", node=a.node_id,
                           model=a.model_name, quantize=a.quantize,
-                          bytes=a.bytes, real=real)
+                          bytes=a.bytes, real=inst.engine is not None)
             keys.append(key)
         return keys
 
@@ -220,7 +236,7 @@ class SDAIController:
         # 3. elastic join: nodes present in fleet but not registered
         for nid, node in list(self.fleet.nodes.items()):
             if node.alive and nid not in self.nodes.payloads:
-                self.nodes.register(node.discovery_payload())
+                self._register(node)
                 self.monitor.observe_heartbeat(nid)
                 self.bus.emit("node_joined", node=nid)
                 self._rebalance_into(nid)
@@ -229,7 +245,7 @@ class SDAIController:
                 self._dead_nodes.discard(nid)
                 self.monitor.clear_mark(nid)
                 self.monitor.observe_heartbeat(nid)
-                self.nodes.register(node.discovery_payload())
+                self._register(node)
                 self.bus.emit("node_recovered", node=nid)
                 self._rebalance_into(nid)
         # 4. load feedback -> scale-up under sustained pressure

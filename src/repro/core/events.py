@@ -14,9 +14,13 @@ REQUEST_MIGRATED = "request_migrated"   # mid-stream resume on a new replica
 NODE_SUSPECTED = "node_suspected"       # demoted in weighted routing
 WATCHDOG_FIRED = "watchdog_fired"       # a pump step blew its deadline
 FAULT_INJECTED = "fault_injected"       # chaos harness applied a fault
+ENGINE_FAILED = "engine_failed"         # an engine step raised; engine down
+PUMP_ERROR = "pump_error"               # a node's pump loop raised
+TICK_ERROR = "tick_error"               # a controller tick raised
 
 FAILURE_EVENT_KINDS = (REQUEST_MIGRATED, NODE_SUSPECTED, WATCHDOG_FIRED,
-                       FAULT_INJECTED)
+                       FAULT_INJECTED, ENGINE_FAILED, PUMP_ERROR,
+                       TICK_ERROR)
 
 
 @dataclasses.dataclass

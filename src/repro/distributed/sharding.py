@@ -142,7 +142,6 @@ def make_tp_projector(mesh: Optional[Mesh], act_strategy: Optional[Strategy],
     """
     if mesh is None or act_strategy is None or w_strategy is None:
         return None
-    from jax.experimental.shard_map import shard_map
     tp = _tp(mesh)[0]
     tp_size = dict(zip(mesh.axis_names, mesh.devices.shape))[tp]
 
@@ -176,10 +175,10 @@ def make_tp_projector(mesh: Optional[Mesh], act_strategy: Optional[Strategy],
                                         scatter_dimension=scatter_axis,
                                         tiled=True)
 
-        return shard_map(body, mesh=mesh,
-                         in_specs=(x_spec, w_spec),
-                         out_specs=P(*out_parts),
-                         check_rep=False)(x, w)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(x_spec, w_spec),
+                             out_specs=P(*out_parts),
+                             check_vma=False)(x, w)
 
     return project
 
@@ -198,7 +197,6 @@ def make_tp_col_projector(mesh: Optional[Mesh],
     """
     if mesh is None or act_strategy is None or w_strategy is None:
         return None
-    from jax.experimental.shard_map import shard_map
     tp = _tp(mesh)[0]
 
     def project(x, w, eq, x_axes, w_axes, out_axes, gather_axis=1):
@@ -225,8 +223,8 @@ def make_tp_col_projector(mesh: Optional[Mesh],
                                         tiled=True)
             return jnp.einsum(eq, x_full, w_)
 
-        return shard_map(body, mesh=mesh, in_specs=(x_spec, w_spec),
-                         out_specs=out_spec, check_rep=False)(x, w)
+        return jax.shard_map(body, mesh=mesh, in_specs=(x_spec, w_spec),
+                             out_specs=out_spec, check_vma=False)(x, w)
 
     return project
 
@@ -242,7 +240,6 @@ def make_tp_gather(mesh: Optional[Mesh],
     """
     if mesh is None or act_strategy is None:
         return None
-    from jax.experimental.shard_map import shard_map
     tp = _tp(mesh)[0]
 
     def gather(x, x_axes, gather_axis: int = 1):
@@ -259,8 +256,8 @@ def make_tp_gather(mesh: Optional[Mesh],
             return jax.lax.all_gather(x_, tp, axis=gather_axis,
                                       tiled=True)
 
-        return shard_map(body, mesh=mesh, in_specs=(x_spec,),
-                         out_specs=P(*out_parts), check_rep=False)(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=(x_spec,),
+                             out_specs=P(*out_parts), check_vma=False)(x)
 
     return gather
 

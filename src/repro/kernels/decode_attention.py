@@ -24,10 +24,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# the installed toolchain may predate the CompilerParams rename
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *,
@@ -132,7 +128,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes[0],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos, q, k_cache, v_cache)

@@ -22,10 +22,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# the installed toolchain may predate the CompilerParams rename
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                   sm_scale: float, block_q: int, block_k: int,
@@ -129,7 +125,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

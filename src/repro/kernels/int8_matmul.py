@@ -17,10 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the installed toolchain may predate the CompilerParams rename
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _int8_mm_kernel(x_ref, w_ref, s_ref, o_ref, acc, *, block_k: int):
     ki = pl.program_id(2)
@@ -66,7 +62,7 @@ def int8_matmul(x, w_q, scale, *, block_m: int = 128, block_n: int = 128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, ki: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_q, scale)

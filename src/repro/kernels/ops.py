@@ -16,11 +16,16 @@ from repro.kernels.int8_matmul import int8_matmul as _int8_mm
 from repro.kernels.paged_attention import (
     paged_decode_attention as _paged_decode,
     paged_decode_attention_ref as _paged_decode_ref,
+    paged_suffix_attention as _paged_suffix,
     paged_suffix_attention_ref as _paged_suffix_ref)
 
-# interpret=True everywhere on CPU (the TPU target compiles the same calls
-# with interpret=False)
-_INTERPRET = jax.default_backend() == "cpu"
+
+def interpret_mode() -> bool:
+    """True where no TPU backs the computation: Pallas kernels then run in
+    interpret mode (and the paged wrappers take their jnp references).
+    Asked at trace time, never at import, so importing this module
+    initialises no backend."""
+    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "prefix",
@@ -28,41 +33,45 @@ _INTERPRET = jax.default_backend() == "cpu"
 def flash_attention(q, k, v, *, causal=True, window=0, prefix=0,
                     block_q=128, block_k=128):
     return _flash(q, k, v, causal=causal, window=window, prefix=prefix,
-                  block_q=block_q, block_k=block_k, interpret=_INTERPRET)
+                  block_q=block_q, block_k=block_k,
+                  interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("window", "prefix", "block_k"))
 def decode_attention(q, k_cache, v_cache, pos, *, window=0, prefix=0,
                      block_k=256):
     return _decode(q, k_cache, v_cache, pos, window=window, prefix=prefix,
-                   block_k=block_k, interpret=_INTERPRET)
+                   block_k=block_k, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
                                              "block_k"))
 def int8_matmul(x, w_q, scale, *, block_m=128, block_n=128, block_k=128):
     return _int8_mm(x, w_q, scale, block_m=block_m, block_n=block_n,
-                    block_k=block_k, interpret=_INTERPRET)
+                    block_k=block_k, interpret=interpret_mode())
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
                            window=0, prefix=0):
     """Page-table-direct decode attention.  Routes to the Pallas kernel
-    on accelerator backends; the jittable fori_loop reference runs the
-    identical schedule on CPU and whenever `window` is traced (hymba's
-    per-layer global/local mix)."""
-    if _INTERPRET or not isinstance(window, int):
+    on TPU; the jittable fori_loop reference runs the identical schedule
+    elsewhere and whenever `window` is traced (hymba's per-layer
+    global/local mix)."""
+    if interpret_mode() or not isinstance(window, int):
         return _paged_decode_ref(q, k_pool, v_pool, page_table, pos,
                                  window=window, prefix=prefix)
     return _paged_decode(q, k_pool, v_pool, page_table, pos,
                          window=window, prefix=prefix)
 
 
-def paged_suffix_attention(q, k_pool, v_pool, page_table, q_pos):
-    """Multi-query paged attention for speculative verify (plain causal);
-    pure-jnp reference on every backend — the verify dispatch is tiny
-    (Q = spec_draft + 1 rows)."""
-    return _paged_suffix_ref(q, k_pool, v_pool, page_table, q_pos)
+def paged_suffix_attention(q, k_pool, v_pool, page_table, pos):
+    """Multi-query paged attention for speculative verify (plain causal):
+    q (B, Q, H, hd) holds Q consecutive tokens per slot, the first at
+    `pos` (B,).  Pallas kernel on TPU, the jnp reference elsewhere."""
+    if interpret_mode():
+        q_pos = pos[:, None] + jnp.arange(q.shape[1], dtype=jnp.int32)
+        return _paged_suffix_ref(q, k_pool, v_pool, page_table, q_pos)
+    return _paged_suffix(q, k_pool, v_pool, page_table, pos)
 
 
 # --------------------------------------------------------------------- #
@@ -99,8 +108,6 @@ def decode_attention_sharded(mesh: Mesh, axis: str):
     `axis`; each shard computes flash-decode partials locally, then a pair
     of small all-reduces (max + weighted sums) merges them — wire cost
     O(B*H*hd) instead of O(B*H*S)."""
-    from jax.experimental.shard_map import shard_map
-
     def fn(q, k_cache, v_cache, pos):
         b, nkv, g, hd = q.shape
         s = k_cache.shape[2]
@@ -118,7 +125,7 @@ def decode_attention_sharded(mesh: Mesh, axis: str):
             return (num_g / jnp.maximum(l_g[..., None], 1e-30)) \
                 .astype(q_.dtype)
 
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(), P(None, None, axis, None),
                       P(None, None, axis, None), P()),

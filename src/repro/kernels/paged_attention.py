@@ -4,14 +4,17 @@ the slot page table — no gathered logical view ever materializes.
 The physical cache is the engine's flat page pool, (n_pages, page_size,
 kv_heads, head_dim) per layer; each slot owns one row of the
 ``(n_slots, pages_per_slot)`` page table whose unused entries hold the
-OOB sentinel ``n_pages``.  The kernel grid is (slots, kv_heads,
-page_blocks) with the page axis sequential, carrying partial-softmax
-state (m, l, acc) in VMEM scratch exactly like ``decode_attention`` —
-but the K/V BlockSpec index maps read the *page table* (scalar-prefetch,
-SMEM-resident) to pick which physical page streams in next, vLLM
-PagedAttention-style.  Sentinel entries are clamped for the DMA and
-masked to -inf in-kernel, so partially-filled tables cost masked lanes,
-never wrong output.
+OOB sentinel ``n_pages``.  The kernel grid is (slots, page_blocks) with
+the page axis sequential, carrying partial-softmax state (m, l, acc) in
+VMEM scratch exactly like ``decode_attention`` — but the K/V BlockSpec
+index maps read the *page table* (scalar-prefetch, SMEM-resident) to
+pick which physical page streams in next, vLLM PagedAttention-style.
+Each step loads the whole page, every KV head of it: the TPU tiling
+refuses a one-head slice of the pool's trailing (kv_heads, head_dim)
+dims.  Sentinel entries are clamped for the DMA and masked to -inf
+in-kernel, so partially-filled tables cost masked lanes, never wrong
+output.  ``paged_suffix_attention`` runs the same kernel with a few
+query rows per slot for speculative verify.
 
 ``paged_decode_attention_ref`` is the jittable ``lax.fori_loop``
 reference the tier-1 CPU suite (and the engine on CPU backends) runs:
@@ -31,10 +34,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-# the installed toolchain may predate the CompilerParams rename
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
 
 
 # ------------------------------------------------------------------ #
@@ -136,17 +135,19 @@ def paged_suffix_attention_ref(q, k_pool, v_pool, page_table, q_pos):
 
 
 # ------------------------------------------------------------------ #
-# Pallas kernel (one physical page per sequential grid step)
+# Pallas kernel (one physical page, every KV head, per grid step)
 # ------------------------------------------------------------------ #
-def _paged_decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, sm_scale: float,
-                         page_size: int, n_pages: int, window: int,
-                         prefix: int):
+def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+                  m_scr, l_scr, acc_scr, *, sm_scale: float,
+                  page_size: int, n_pages: int, window: int, prefix: int,
+                  group: int):
     bi = pl.program_id(0)
-    ij = pl.program_id(2)
-    nj = pl.num_programs(2)
+    ij = pl.program_id(1)
+    nj = pl.num_programs(1)
     pos = pos_ref[bi]
     page = table_ref[bi, ij]
+    rows = q_ref.shape[1]
+    last = pos + (rows // group - 1)        # the latest query position
 
     @pl.when(ij == 0)
     def _init():
@@ -156,7 +157,7 @@ def _paged_decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     # skip sentinel pages and pages entirely past the valid region
     blk_start = ij * page_size
-    run = jnp.logical_and(page < n_pages, blk_start <= pos)
+    run = jnp.logical_and(page < n_pages, blk_start <= last)
     if window > 0:
         in_reach = (blk_start + page_size - 1) > (pos - window)
         if prefix > 0:
@@ -165,38 +166,83 @@ def _paged_decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)              # (ps, hd)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (G, ps)
+        k = k_ref[0].astype(jnp.float32)                    # (ps, K, hd)
+        v = v_ref[0].astype(jnp.float32)
         kv_pos = blk_start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        mask = kv_pos <= pos
-        if window > 0:
-            inwin = kv_pos > pos - window
-            if prefix > 0:
-                inwin = jnp.logical_or(inwin, kv_pos < prefix)
-            mask = jnp.logical_and(mask, inwin)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1,
-                                                 keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)              # (ps, hd)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (G, hd)
-        acc_scr[...] = acc_scr[...] * corr + pv
-        m_scr[...] = m_new
+            jnp.int32, (page_size, k.shape[1], 1), 0)       # (ps, K, 1)
+        # decode is one query row per head: scores and the weighted sum
+        # are lane reductions on the VPU, so the page block keeps its
+        # pool layout and no head is sliced out of the tiled (K, hd) dims
+        for r in range(rows):
+            q_at = pos + r // group
+            q = q_ref[0, r].astype(jnp.float32) * sm_scale  # (K, hd)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # (ps, K, 1)
+            mask = kv_pos <= q_at
+            if window > 0:
+                inwin = kv_pos > q_at - window
+                if prefix > 0:
+                    inwin = jnp.logical_or(inwin, kv_pos < prefix)
+                mask = jnp.logical_and(mask, inwin)
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_scr[r]                                # (K, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.where(mask, jnp.exp(s - m_new[None]), 0.0)
+            l_scr[r] = l_scr[r] * corr + jnp.sum(p, axis=0)
+            acc_scr[r] = acc_scr[r] * corr + jnp.sum(p * v, axis=0)
+            m_scr[r] = m_new
 
     @pl.when(ij == nj - 1)
     def _finish():
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] /
+                    jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_call(q, k_pool, v_pool, page_table, pos, *, group: int,
+                window: int, prefix: int, interpret: bool):
+    """q: (B, R, K, hd) query rows; row r sits at position
+    pos + r // group.  Returns (B, R, K, hd)."""
+    b, rows, nkv, hd = q.shape
+    n_pages, ps, _, _ = k_pool.shape
+    pps = page_table.shape[1]
+    kernel = functools.partial(
+        _paged_kernel, sm_scale=hd ** -0.5, page_size=ps,
+        n_pages=n_pages, window=window, prefix=prefix, group=group)
+
+    # sentinel entries still drive the DMA index map: clamp them to a
+    # real page (the kernel masks the whole block, so the data is dead)
+    def kv_map(bi, ij, table_ref, _pos):
+        return (jnp.minimum(table_ref[bi, ij], n_pages - 1), 0, 0, 0)
+
+    def q_map(bi, ij, _t, _p):
+        return (bi, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, pps),
+        in_specs=[
+            pl.BlockSpec((1, rows, nkv, hd), q_map),
+            # the whole page: (ps, K, hd) are the pool's full trailing
+            # dims, which the TPU tiling accepts for any K and hd
+            pl.BlockSpec((1, ps, nkv, hd), kv_map),
+            pl.BlockSpec((1, ps, nkv, hd), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, rows, nkv, hd), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((rows, nkv, 1), jnp.float32),
+            pltpu.VMEM((rows, nkv, 1), jnp.float32),
+            pltpu.VMEM((rows, nkv, hd), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, rows, nkv, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_attention",
+    )(page_table, pos, q, k_pool, v_pool)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
@@ -206,41 +252,25 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
     (P, ps, K, hd); page_table: (B, pps) int32 with sentinel == P; pos:
     (B,) int32.  Returns (B, K, G, hd).  `window`/`prefix` must be
     static here — callers with traced windows use the ref."""
-    b, nkv, g, hd = q.shape
-    n_pages, ps, _, _ = k_pool.shape
-    pps = page_table.shape[1]
-    grid = (b, nkv, pps)
-    kernel = functools.partial(
-        _paged_decode_kernel, sm_scale=hd ** -0.5, page_size=ps,
-        n_pages=n_pages, window=window, prefix=prefix)
+    g = q.shape[2]
+    out = _paged_call(q.transpose(0, 2, 1, 3), k_pool, v_pool, page_table,
+                      pos, group=g, window=window, prefix=prefix,
+                      interpret=interpret)
+    return out.transpose(0, 2, 1, 3)
 
-    # sentinel entries still drive the DMA index map: clamp them to a
-    # real page (the kernel masks the whole block, so the data is dead)
-    def kv_map(bi, hi, ij, table_ref, _pos):
-        return (jnp.minimum(table_ref[bi, ij], n_pages - 1), 0, hi, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda bi, hi, ij, _t, _p: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda bi, hi, ij, _t, _p: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(page_table, pos, q, k_pool, v_pool)
+def paged_suffix_attention(q, k_pool, v_pool, page_table, pos, *,
+                           interpret: bool = False):
+    """Pallas multi-query paged attention for speculative verify: q (B,
+    Q, H, hd) holds Q consecutive tokens per slot, the first at `pos`
+    (B,); causal by absolute position, plain causal only.  Same result as
+    ``paged_suffix_attention_ref`` with q_pos = pos + arange(Q)."""
+    b, qn, h, hd = q.shape
+    nkv = k_pool.shape[2]
+    grp = h // nkv
+    rows = q.reshape(b, qn, nkv, grp, hd).transpose(0, 1, 3, 2, 4) \
+        .reshape(b, qn * grp, nkv, hd)
+    out = _paged_call(rows, k_pool, v_pool, page_table, pos, group=grp,
+                      window=0, prefix=0, interpret=interpret)
+    return out.reshape(b, qn, grp, nkv, hd).transpose(0, 1, 3, 2, 4) \
+        .reshape(b, qn, h, hd)

@@ -954,7 +954,7 @@ def spec_verify_paged(params, cfg: ArchConfig, cache, tokens, pos,
         kc = _paged_write(kc, k_new, write_table, q_pos)
         vc = _paged_write(vc, v_new, write_table, q_pos)
         a_out = kernel_ops.paged_suffix_attention(q, kc, vc,
-                                                  page_table, q_pos)
+                                                  page_table, pos)
         h = h + jnp.einsum("bshk,hkd->bsd", a_out, lp["attn"]["wo"])
         x = L.norm(h, lp.get("ln2"), cfg.norm)
         f_out, _ = _ffn(lp, cfg, x, sh)

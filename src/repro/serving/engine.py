@@ -111,13 +111,18 @@ class InferenceEngine:
     """One model instance on one node."""
 
     def __init__(self, cfg: ArchConfig, params, engine_cfg: EngineConfig,
-                 scheduler: Optional[Scheduler] = None):
+                 scheduler: Optional[Scheduler] = None,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
         self.ecfg = engine_cfg
+        # every array this engine holds is committed to one device, so
+        # its jitted steps run there whichever thread dispatches them
+        self.device = device if device is not None else jax.devices()[0]
         self.model = build(cfg)
         self.scheduler = scheduler or Scheduler(SchedulerConfig())
         self._dead = False
-        self._key = jax.random.PRNGKey(engine_cfg.seed)
+        self._key = jax.device_put(jax.random.PRNGKey(engine_cfg.seed),
+                                   self.device)
         # recurrent families fold right-pads into their state, so they
         # batch prefills at exact lengths instead of padded buckets
         self._supports_bucket = cfg.block not in ("xlstm", "hymba")
@@ -163,6 +168,7 @@ class InferenceEngine:
             if engine_cfg.prefix_cache and self._prefix_ok else None)
         self._swapped: Dict[int, Any] = {}   # request_id -> SwapHandle
 
+        params = jax.device_put(params, self.device)
         if engine_cfg.quantize:
             bits = 8 if engine_cfg.quantize == "int8" else 4
             self.params = q_lib.quantize_tree(params, bits=bits)
@@ -177,20 +183,21 @@ class InferenceEngine:
         # persistent per-slot device state: touched only by jitted
         # admission / fused-decode calls and the (rare) cancel path
         ns = engine_cfg.n_slots
-        self.pos = jnp.zeros((ns,), jnp.int32)
-        self.last_tok = jnp.zeros((ns,), jnp.int32)
-        self.active = jnp.zeros((ns,), bool)
-        self.remaining = jnp.zeros((ns,), jnp.int32)
-        self.temps = jnp.zeros((ns,), jnp.float32)
-        self.top_ks = jnp.zeros((ns,), jnp.int32)
-        self.top_ps = jnp.ones((ns,), jnp.float32)
-        self.eos_ids = jnp.full((ns,), -1, jnp.int32)
+        dev = self.device
+        self.pos = jnp.zeros((ns,), jnp.int32, device=dev)
+        self.last_tok = jnp.zeros((ns,), jnp.int32, device=dev)
+        self.active = jnp.zeros((ns,), bool, device=dev)
+        self.remaining = jnp.zeros((ns,), jnp.int32, device=dev)
+        self.temps = jnp.zeros((ns,), jnp.float32, device=dev)
+        self.top_ks = jnp.zeros((ns,), jnp.int32, device=dev)
+        self.top_ps = jnp.ones((ns,), jnp.float32, device=dev)
+        self.eos_ids = jnp.full((ns,), -1, jnp.int32, device=dev)
         # speculative-decoding device state: per-slot bigram proposer
         # table plus the token *preceding* last_tok (the chain seed) —
         # wiped on admission/release so a reused slot never proposes
         # from another request's stream
-        self.spec_table, self.spec_prev = spec_lib.init_tables(
-            ns, engine_cfg.spec_table)
+        self.spec_table, self.spec_prev = jax.device_put(
+            spec_lib.init_tables(ns, engine_cfg.spec_table), dev)
         # logical KV bytes one fused dispatch moves: the gather path
         # copies every slot's logical view out and back (2x view); the
         # page-table path only writes K new tokens' KV in place
@@ -237,16 +244,18 @@ class InferenceEngine:
         cross-attention KV, the whole xlstm state) stay slot-resident."""
         ns, ml = self.ecfg.n_slots, self.ecfg.max_len
         if not self._paged:
-            return self.model.init_cache(ns, ml, src_len=src_len)
+            with jax.default_device(self.device):
+                cache = self.model.init_cache(ns, ml, src_len=src_len)
+            return jax.device_put(cache, self.device)
         spec = jax.eval_shape(
             lambda: self.model.init_cache(ns, ml, src_len=src_len))
         paged, resident = split_paged(spec)
-        cache = {name: jnp.zeros(s.shape, s.dtype)
+        cache = {name: jnp.zeros(s.shape, s.dtype, device=self.device)
                  for name, s in resident.items()}
         for name, s in paged.items():
             cache[name] = jnp.zeros(
                 (s.shape[0], self.pool.n_pages, self.pool.page_size)
-                + s.shape[3:], s.dtype)
+                + s.shape[3:], s.dtype, device=self.device)
         return cache
 
     def _pages_for(self, req: Request) -> int:
@@ -576,8 +585,10 @@ class InferenceEngine:
             return False
         return self.scheduler.submit(req)
 
-    def fail(self):
-        """Failure injection: node/instance crash."""
+    def fail(self, reason: str = ""):
+        """Take the engine down (node/instance crash, or a step that
+        raised): every queued and in-flight request finishes with
+        ENGINE_FAILED, carrying `reason` when one is given."""
         self._dead = True
         doomed = list(self.slot_req.values())
         self.slot_req.clear()
@@ -585,8 +596,9 @@ class InferenceEngine:
         # landed in the queue (doomed below) or is rejected by the closed
         # scheduler with ENGINE_FAILED — the frontend fails it over
         doomed += self.scheduler.close()
+        error = f"engine crashed: {reason}" if reason else "engine crashed"
         for req in doomed:
-            req.finish(error="engine crashed", code=CODE_ENGINE_FAILED)
+            req.finish(error=error, code=CODE_ENGINE_FAILED)
 
     def cancel(self, request_id: int):
         """Abort a queued or in-flight request, freeing its slot and

@@ -10,7 +10,8 @@ from repro.cluster import BackendNode, FaultInjector, FaultSpec, Fleet
 from repro.configs import ARCHS
 from repro.core import (ModelCatalog, ReplicaInfo, ReplicaKey,
                         SDAIController)
-from repro.core.events import (FAULT_INJECTED, NODE_SUSPECTED,
+from repro.core.events import (ENGINE_FAILED, FAULT_INJECTED,
+                               NODE_SUSPECTED, PUMP_ERROR,
                                REQUEST_MIGRATED, WATCHDOG_FIRED)
 from repro.core.health import NodeHealth
 from repro.serving import SamplingParams
@@ -336,3 +337,41 @@ def test_swap_fail_window_forces_recompute_fallback(param_store):
         if inst.engine is not None and inst.engine.host_pool is not None:
             assert not inst.engine.host_pool.fail_puts
     inj.uninstall()
+
+
+def test_engine_step_exception_fails_engine_and_is_recorded(param_store):
+    """An engine step that raises (a compile error or device fault on a
+    chip) fails that engine: the bus records it, its stream migrates to
+    the surviving replica and completes, and the node's pump thread
+    keeps running."""
+    fleet, ctrl = _pinned_stack(param_store, n_nodes=2)
+    gw = Gateway(ctrl)
+    victim = next(iter(fleet.nodes))
+    [inst] = fleet.nodes[victim].instances.values()
+    real_step, calls = inst.engine.step, []
+
+    def step():
+        calls.append(1)
+        if len(calls) == 2:          # after the first token: mid-stream
+            raise RuntimeError("injected device fault")
+        return real_step()
+
+    inst.engine.step = step
+    rt = gw.start(RuntimeConfig(tick_interval_s=0.01))
+    try:
+        h = None
+        for prompt in ([5, 6], [7, 8], [9, 10], [11, 12]):
+            h = gw.submit(MODEL, prompt, SamplingParams(max_tokens=8))
+            if h.internal.node == victim:
+                break
+        assert h.internal.node == victim
+        resp = h.result(timeout_s=120)
+        assert resp.ok and len(resp.tokens) == 8
+        [ev] = ctrl.bus.of_kind(ENGINE_FAILED)
+        assert ev.data["node"] == victim
+        assert "injected device fault" in ev.data["error"]
+        assert not inst.engine.alive
+        assert rt._pumps[victim].is_alive()
+        assert not ctrl.bus.of_kind(PUMP_ERROR)
+    finally:
+        assert gw.stop(timeout_s=60) is True

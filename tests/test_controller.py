@@ -4,7 +4,8 @@ import dataclasses
 
 import pytest
 
-from repro.cluster import BackendNode, paper_testbed, scale_fleet
+from repro.cluster import BackendNode, Fleet, paper_testbed, scale_fleet
+from repro.cluster.node import DeployError
 from repro.configs import ZOO
 from repro.core import (Client, ConfigWizard, ControllerConfig,
                         ModelCatalog, ModelDemand, SDAIController,
@@ -151,3 +152,34 @@ def test_scale_fleet_thousand_nodes():
     ctrl.tick()
     for m in ("llama3.2-1b", "deepseek-r1-7b", "qwen3-8b"):
         assert ctrl.frontend.healthy_replicas(m), f"{m} lost all replicas"
+
+
+def test_real_deploy_without_params_is_reported_not_simulated(param_store):
+    """A model under the real-engine threshold on a node with no weights
+    for it: the node refuses, the controller reports `deploy_failed`,
+    and nothing serves synthetic tokens in its place."""
+    tiny = dataclasses.replace(ZOO["llama3.2-1b"].reduced(),
+                               name="llama3.2-1b")
+    node = BackendNode("bare", "v5e-1")            # no param store
+    with pytest.raises(DeployError):
+        node.deploy(tiny, n_slots=2, max_len=48)
+    assert node.instances == {}
+    catalog = ModelCatalog()
+    catalog.register(tiny)
+    ctrl = SDAIController(Fleet([node]), catalog, ControllerConfig())
+    ctrl.discover()
+    ctrl.deploy([ModelDemand(tiny, min_replicas=1, max_replicas=1,
+                             n_slots=2, max_len=48)])
+    assert [e.data["model"] for e in ctrl.bus.of_kind("deploy_failed")] \
+        == ["llama3.2-1b"]
+    assert not ctrl.bus.of_kind("instance_deployed")
+    assert ctrl.replicas.for_model("llama3.2-1b") == []
+    assert node.instances == {}
+    # with weights, the same deploy reports the engine it built
+    node.param_store = param_store
+    ctrl.deploy([ModelDemand(tiny, min_replicas=1, max_replicas=1,
+                             n_slots=2, max_len=48)])
+    [ev] = ctrl.bus.of_kind("instance_deployed")
+    assert ev.data["real"] is True
+    [inst] = node.instances.values()
+    assert inst.engine is not None

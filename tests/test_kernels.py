@@ -1,31 +1,19 @@
 """Pallas kernel validation: shape/dtype sweeps, assert_allclose against the
 pure-jnp ref.py oracles (interpret=True executes kernel bodies on CPU).
-
-Every kernel resolves the compiler-params constructor through a compat
-alias (``CompilerParams`` on current toolchains, ``TPUCompilerParams``
-on older ones), so this module runs green on CPU CI.  The lone skip
-below guards the one toolchain shape where *neither* attribute exists —
-there the kernels cannot even be constructed, and only that exact
-condition may quarantine anything here.
+Whether the kernels also compile for the chip is checked in
+``tests/test_tpu_compile.py``.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 
-if not (hasattr(pltpu, "CompilerParams")
-        or hasattr(pltpu, "TPUCompilerParams")):   # pragma: no cover
-    pytest.skip("installed jax's pallas.tpu exposes no compiler-params "
-                "API at all — kernels cannot be constructed",
-                allow_module_level=True)
-
-from repro.kernels import ops, ref  # noqa: E402
-from repro.kernels import paged_attention as pa  # noqa: E402
-from repro.kernels.decode_attention import decode_attention  # noqa: E402
-from repro.kernels.flash_attention import flash_attention  # noqa: E402
-from repro.kernels.int8_matmul import int8_matmul  # noqa: E402
-from repro.serving.quantization import quantize_array  # noqa: E402
+from repro.kernels import ops, ref
+from repro.kernels import paged_attention as pa
+from repro.kernels.decode_attention import decode_attention
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.int8_matmul import int8_matmul
+from repro.serving.quantization import quantize_array
 
 rng = np.random.default_rng(7)
 
@@ -249,6 +237,9 @@ def test_paged_suffix_vs_dense_oracle():
     q = rnd(B, Q, H, hd)
     q_pos = jnp.asarray(pos0, jnp.int32)[:, None] + jnp.arange(Q)[None, :]
     out = pa.paged_suffix_attention_ref(q, kp, vp, table, q_pos)
+    outk = pa.paged_suffix_attention(q, kp, vp, table,
+                                     jnp.asarray(pos0, jnp.int32),
+                                     interpret=True)
     kc, vc = _gather_view(kp, table), _gather_view(vp, table)
     # dense oracle: fold H -> (K, G) K-major, mask kv_pos <= q_pos
     qf = q.reshape(B, Q, K, G, hd).astype(jnp.float32) * hd ** -0.5
@@ -260,4 +251,6 @@ def test_paged_suffix_vs_dense_oracle():
     expect = jnp.einsum("bqkgs,bksd->bqkgd", p,
                         vc.astype(jnp.float32)).reshape(B, Q, H, hd)
     np.testing.assert_allclose(out, expect.astype(out.dtype),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(outk, expect.astype(out.dtype),
                                atol=2e-5, rtol=2e-5)

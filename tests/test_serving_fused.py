@@ -63,16 +63,33 @@ def test_done_mask_stops_slots_mid_block(cfg, params):
     assert all(r.state == RequestState.FINISHED for r in reqs)
 
 
+def _first_new_token(out):
+    """Index of the first decoded token (past the prefill-sampled one)
+    that has not appeared earlier in the stream, or None."""
+    for i in range(1, len(out)):
+        if out[i] not in out[:i]:
+            return i
+    return None
+
+
 def test_eos_stops_mid_block(cfg, params):
     eng = _engine(cfg, params, decode_block=8)
-    probe = Request(model="m", prompt=[5, 6],
-                    sampling=SamplingParams(max_tokens=10))
-    _run(eng, [probe])
-    eos = probe.output[3]                  # 4th greedy token as EOS
-    r = Request(model="m", prompt=[5, 6],
-                sampling=SamplingParams(max_tokens=10, eos_id=eos))
+    # a greedy stream may repeat one token forever; probe prompts until
+    # one yields a token unseen before it, so EOS can't fire earlier
+    for prompt in ([5, 6], [1, 2, 3], [7], [11, 13, 17, 19], [40, 41]):
+        probe = Request(model="m", prompt=prompt,
+                        sampling=SamplingParams(max_tokens=10))
+        _run(eng, [probe])
+        idx = _first_new_token(probe.output)
+        if idx is not None:
+            break
+    assert idx is not None, "no probe prompt yields a fresh token"
+    r = Request(model="m", prompt=prompt,
+                sampling=SamplingParams(max_tokens=10,
+                                        eos_id=probe.output[idx]))
     _run(eng, [r])
-    assert r.output == probe.output[:4]    # stopped exactly at EOS
+    assert r.output == probe.output[:idx + 1]    # stopped exactly at EOS
+    assert r.state == RequestState.FINISHED
 
 
 # ------------------- bucketed prefill ------------------------------ #

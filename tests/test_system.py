@@ -82,7 +82,8 @@ def test_sharded_train_step_matches_unsharded():
     s1 = init1(jax.random.PRNGKey(0))
     s1b, m1 = jax.jit(step1)(s1, batch)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     strat = train_strategy_fsdp(mesh)
     stepN, initN = make_train_step(cfg, mesh, strat)
     with mesh:
@@ -169,7 +170,8 @@ def test_fsdp_tp_shard_map_projections_exact():
     s1 = init1(jax.random.PRNGKey(0))
     _, m1 = jax.jit(step1)(s1, batch)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     strat = train_strategy(mesh)          # fsdp_tp: uses shard_map paths
     stepN, initN = make_train_step(cfg, mesh, strat)
     with mesh:
