@@ -140,7 +140,11 @@ RECEIVER_CLASS_HINTS: Dict[str, str] = {
     "handle": "GenerationHandle",
     "rt": "ServingRuntime", "runtime": "ServingRuntime",
     "tenants": "TenantLimiter",
+    "_phase": "Phases",
 }
+# Receiver-name -> module hints: `obs.record()` binds to the function
+# `record` of the module whose path ends so, not every `record`.
+RECEIVER_MODULE_HINTS: Dict[str, str] = {"obs": "repro/obs.py"}
 
 
 def _is_frozen_dataclass_decorator(dec: ast.expr) -> Optional[bool]:
@@ -196,17 +200,25 @@ class ProjectIndex:
             self.by_class.setdefault(fi.cls, {})[fi.name] = fi
 
     # -------------------------------------------------------------- #
-    def resolve_call(self, call: ast.Call,
-                     caller_cls: Optional[str]) -> List[FunctionInfo]:
+    def resolve_call(self, call: ast.Call, caller_cls: Optional[str],
+                     caller_module: Optional[SourceModule] = None
+                     ) -> List[FunctionInfo]:
         """Candidate targets for a call site.  `self.f()` binds to the
         caller's own class when it defines `f`; a hinted receiver binds
         to that class only (empty when the class lacks the method —
         a confident receiver with an unknown method is external code);
-        anything else falls back to every function with that bare name."""
+        a bare `f()` binds to its own module's top-level `f` where there
+        is one; anything else falls back to every function with that
+        bare name."""
         name = call_name(call)
         if name is None:
             return []
         recv = call_receiver(call)
+        if recv is None and caller_module is not None:
+            own = [fi for fi in self.by_name.get(name, [])
+                   if fi.cls is None and fi.module is caller_module]
+            if own:
+                return own
         if recv is not None:
             key = recv[-1]
             if key == "self" and caller_cls is not None:
@@ -218,6 +230,10 @@ class ProjectIndex:
             if hinted is not None:
                 meth = self.by_class.get(hinted, {}).get(name)
                 return [meth] if meth is not None else []
+            module = RECEIVER_MODULE_HINTS.get(key)
+            if module is not None and len(recv) == 1:
+                return [fi for fi in self.by_name.get(name, [])
+                        if fi.cls is None and fi.module.rel.endswith(module)]
         return self.by_name.get(name, [])
 
 

@@ -79,7 +79,8 @@ class HotPathSyncChecker(Checker):
             reached[fi.uid] = fi
             for node in ast.walk(fi.node):
                 if isinstance(node, ast.Call):
-                    for target in index.resolve_call(node, fi.cls):
+                    for target in index.resolve_call(node, fi.cls,
+                                                     fi.module):
                         if target.uid not in reached:
                             work.append(target)
 
@@ -121,7 +122,8 @@ def reachable_functions(index: ProjectIndex,
         reached.add(fi.qualname)
         for node in ast.walk(fi.node):
             if isinstance(node, ast.Call):
-                for target in index.resolve_call(node, fi.cls):
+                for target in index.resolve_call(node, fi.cls,
+                                                 fi.module):
                     if target.uid not in seen:
                         work.append(target)
     return reached

@@ -181,7 +181,8 @@ class LockOrderChecker(Checker):
             for fi in index.functions:
                 acc = eventually[fi.uid]
                 for site in scans[fi.uid].calls:
-                    for target in index.resolve_call(site.call, fi.cls):
+                    for target in index.resolve_call(site.call, fi.cls,
+                                                   fi.module):
                         extra = eventually[target.uid] - acc
                         if extra:
                             acc |= extra
@@ -224,7 +225,8 @@ class LockOrderChecker(Checker):
             for site in sc.calls:
                 if not site.held:
                     continue
-                for target in index.resolve_call(site.call, fi.cls):
+                for target in index.resolve_call(site.call, fi.cls,
+                                                   fi.module):
                     for lvl in eventually[target.uid]:
                         for h_lvl, _h_text in site.held:
                             edge_graph.add((h_lvl, lvl))

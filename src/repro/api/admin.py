@@ -12,6 +12,7 @@ import dataclasses
 import time
 from typing import TYPE_CHECKING, Any, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core.events import FAILURE_EVENT_KINDS
 from repro.core.frontend import TenantQuota
 from repro.core.placement import ModelDemand
@@ -65,10 +66,45 @@ class NodeSnapshot:
     hbm_used: int
     hbm_budget: int
     instances: Tuple[InstanceSnapshot, ...]
+    # the serving loop over the last `TIMING_WINDOW_S`, from the span
+    # ring: queue wait to first admission, and the engine's host time
+    # per step net of device waits (None: nothing recorded, or the ring
+    # lost part of the window)
+    queue_wait_p50_ms: Optional[float] = None
+    queue_wait_p95_ms: Optional[float] = None
+    host_ms_per_step: Optional[float] = None
 
     @property
     def utilization(self) -> float:
         return self.hbm_used / self.hbm_budget if self.hbm_budget else 0.0
+
+
+TIMING_WINDOW_S = 60.0
+
+
+def _node_timings(since: float, until: float) -> Dict[str, Dict[str, float]]:
+    """node id -> the `NodeSnapshot` timing fields over [since, until]
+    on the span ring's clock; {} where the ring lost part of it."""
+    waits = obs.records(obs.QUEUE_WAIT, since, until)
+    steps = obs.records(obs.ENGINE_STEP, since, until)
+    syncs = [obs.records(n, since, until) for n in obs.SYNCS]
+    if waits is None or steps is None or None in syncs:
+        return {}
+    out: Dict[str, Dict[str, float]] = {}
+    by_node: Dict[str, List[float]] = {}
+    for w in waits:
+        by_node.setdefault(w.attrs["node"], []).append(w.seconds * 1e3)
+    for nid, ms in by_node.items():
+        out.setdefault(nid, {}).update(
+            queue_wait_p50_ms=obs.percentile(ms, 50),
+            queue_wait_p95_ms=obs.percentile(ms, 95))
+    host = obs.host_seconds(steps, [s for part in syncs for s in part])
+    per_step: Dict[str, List[float]] = {}
+    for st, h in zip(steps, host):
+        per_step.setdefault(st.attrs["node"], []).append(h * 1e3)
+    for nid, ms in per_step.items():
+        out.setdefault(nid, {})["host_ms_per_step"] = sum(ms) / len(ms)
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,6 +186,9 @@ class FleetSnapshot:
                     "health": n.health,
                     "hbm_used": n.hbm_used,
                     "hbm_budget": n.hbm_budget,
+                    "timing": {"queue_wait_p50_ms": n.queue_wait_p50_ms,
+                               "queue_wait_p95_ms": n.queue_wait_p95_ms,
+                               "host_ms_per_step": n.host_ms_per_step},
                     "instances": [{"model": i.model,
                                    "quantize": i.quantize,
                                    "kv_pages": i.kv_pages,
@@ -221,6 +260,8 @@ class AdminAPI:
     # ---- observe ------------------------------------------------- #
     def snapshot(self) -> FleetSnapshot:
         c = self.c
+        now = obs.clock()
+        timings = _node_timings(now - TIMING_WINDOW_S, now)
         nodes: List[NodeSnapshot] = []
         for nid in c.nodes.ids():
             node = c.fleet.nodes.get(nid)
@@ -287,7 +328,7 @@ class AdminAPI:
                 health=c.monitor.status(nid).value,
                 hbm_used=node.hbm_used if node and alive else 0,
                 hbm_budget=node.hbm_budget if node else 0,
-                instances=tuple(instances)))
+                instances=tuple(instances), **timings.get(nid, {})))
         models = tuple(ModelSnapshot(
             name=m, replicas=len(c.replicas.for_model(m)),
             healthy_replicas=len(c.frontend.healthy_replicas(m)))

@@ -27,6 +27,7 @@ import time
 import traceback
 from typing import TYPE_CHECKING, Dict, List, Optional
 
+from repro import obs
 from repro.core.controller import ModelLoad
 from repro.core.events import (NODE_SUSPECTED, PUMP_ERROR, TICK_ERROR,
                                WATCHDOG_FIRED)
@@ -70,9 +71,11 @@ class _NodePump(threading.Thread):
         node, rt = self.node, self.rt
         while True:
             with node.work_cv:
-                while not rt._stopping.is_set() and \
-                        not node.has_work():
-                    node.work_cv.wait(rt.cfg.pump_idle_wait_s)
+                if not rt._stopping.is_set() and not node.has_work():
+                    with obs.span(obs.RUNTIME_IDLE, node=node.node_id):
+                        while not rt._stopping.is_set() and \
+                                not node.has_work():
+                            node.work_cv.wait(rt.cfg.pump_idle_wait_s)
             if rt._stopping.is_set():
                 if not rt._drain or not node.alive:
                     return

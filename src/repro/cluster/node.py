@@ -237,7 +237,7 @@ class BackendNode:
                              paged_attention=paged_attention,
                              speculative=speculative,
                              spec_draft=spec_draft),
-                device=self.device)
+                device=self.device, node=self.node_id)
         inst = Instance(next(_inst_ids), cfg.name, cfg, quantize, n_slots,
                         max_len, need, engine, page_size=page_size,
                         kv_pages=eff_pages)

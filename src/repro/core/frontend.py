@@ -19,6 +19,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from repro import obs
 from repro.cluster.fleet import Fleet
 from repro.configs import ZOO
 from repro.configs.base import ArchConfig
@@ -323,16 +324,21 @@ class ServiceFrontend:
         req._suppress_finish = True
         try:
             for attempt in range(self.cfg.max_retries + 1):
-                key = self.pick(req.model, exclude=tried, bucket=bucket)
+                with obs.span(obs.FRONTEND_ROUTE,
+                              request=req.request_id) as route:
+                    key = self.pick(req.model, exclude=tried, bucket=bucket)
+                    if key is not None:
+                        node = self.fleet.nodes[key.node_id]
+                        t0 = time.monotonic()
+                        ok = node.submit(key.instance_id, req)
+                        route.update(node=key.node_id, replica=str(key),
+                                     ok=ok)
                 if key is None:
                     self.stats.rejected_no_backend += 1
                     req.finish(error="no healthy backend",
                                code=CODE_NO_BACKEND)
                     return False
                 tried.add(key)
-                node = self.fleet.nodes[key.node_id]
-                t0 = time.monotonic()
-                ok = node.submit(key.instance_id, req)
                 if ok:
                     self.stats.routed += 1
                     rk = str(key)

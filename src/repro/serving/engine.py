@@ -42,13 +42,13 @@ and dequantized on-chip per step.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.models import build
 from repro.serving import quantization as q_lib
@@ -112,9 +112,12 @@ class InferenceEngine:
 
     def __init__(self, cfg: ArchConfig, params, engine_cfg: EngineConfig,
                  scheduler: Optional[Scheduler] = None,
-                 device: Optional[jax.Device] = None):
+                 device: Optional[jax.Device] = None, node: str = ""):
         self.cfg = cfg
         self.ecfg = engine_cfg
+        # the serving node's id, carried by this engine's spans
+        self.node = node
+        self._phase = obs.Phases(node=node)
         # every array this engine holds is committed to one device, so
         # its jitted steps run there whichever thread dispatches them
         self.device = device if device is not None else jax.devices()[0]
@@ -218,7 +221,6 @@ class InferenceEngine:
         # metrics
         self.total_tokens = 0
         self.total_steps = 0
-        self.step_ewma_s = 0.0
         self.dispatches = 0       # jitted calls issued
         self.host_syncs = 0       # blocking device->host transfers
         self.prefill_traces = 0   # compile-cache counter: bucketed prefill
@@ -655,13 +657,17 @@ class InferenceEngine:
         emitted."""
         if self._dead:
             raise EngineFailure("engine is dead")
-        t0 = time.monotonic()
-        self._admit()
-        emitted = self._decode_block() if self.slot_req else 0
+        t0 = obs.clock()
+        step = self.total_steps
+        self._phase = obs.Phases(node=self.node, step=step)
+        try:
+            self._admit()
+            emitted = self._decode_block() if self.slot_req else 0
+        finally:
+            self._phase.end()
         self.total_steps += 1
-        dt = time.monotonic() - t0
-        self.step_ewma_s = 0.9 * self.step_ewma_s + 0.1 * dt \
-            if self.total_steps > 1 else dt
+        obs.record(obs.ENGINE_STEP, t0, obs.clock(), node=self.node,
+                   step=step)
         return emitted
 
     # ---- admissions: one bucketed batch prefill dispatch ---------- #
@@ -680,6 +686,7 @@ class InferenceEngine:
         return debt
 
     def _admit(self):
+        self._phase.to(obs.ENGINE_SCHEDULE)
         budget = len(self.pool.free_pages) - self._decode_page_debt()
         if self.prefix_cache is not None:
             # LRU cache pages are reclaimable on demand: count them into
@@ -734,6 +741,7 @@ class InferenceEngine:
             self.prefix_cache.reclaim(short, demote)
 
     def _admit_prefill(self, group: List[Request]):
+        self._phase.to(obs.ENGINE_SCHEDULE)
         admitted: List[Tuple[int, Request]] = []
         for req in group:
             eff = len(req.prompt) + len(req.output)
@@ -747,7 +755,7 @@ class InferenceEngine:
             if slot is None:                    # defensive; the admission
                 self.scheduler.requeue(req)     # budget above bounds the
                 continue                        # group — never drop it
-            req.state = RequestState.PREFILLING
+            self._note_admission(req)
             admitted.append((slot, req))
         if not admitted:
             return
@@ -783,6 +791,7 @@ class InferenceEngine:
             r_budget[i] = s.max_tokens - len(req.output)
             r_prev[i] = prompt[-1]      # precedes the sampled first token
         extra = self._extra_inputs(pad_n)
+        self._phase.to(obs.PREFILL_DISPATCH)
         (self.cache, self.last_tok, self.pos, self.active, self.remaining,
          self.temps, self.top_ks, self.top_ps, self.eos_ids, self._key,
          self.spec_table, self.spec_prev,
@@ -794,14 +803,25 @@ class InferenceEngine:
             r_temps, r_topk, r_topp, r_eos, r_budget, r_prev, extra)
         self.dispatches += 1
         self.prefill_dispatch_tokens += pad_n * bucket
+        self._phase.to(obs.PREFILL_SYNC)
         first_h, done_h = jax.device_get((first, done0))
         self.host_syncs += 1
         self._post_admit(admitted, first_h, done_h)
+
+    def _note_admission(self, req: Request):
+        """A request takes a slot: at its first admission, record how
+        long it waited (a preempted request's resume records nothing)."""
+        req.state = RequestState.PREFILLING
+        if req.admitted_at is None:
+            req.admitted_at = obs.clock()
+            obs.record(obs.QUEUE_WAIT, req.created_at, req.admitted_at,
+                       node=self.node, request=req.request_id)
 
     def _post_admit(self, admitted: List[Tuple[int, Request]],
                     first_h, done_h):
         """Shared tail of both admission dispatches: emit each row's
         first sampled token, then park it in its slot (or finish it)."""
+        self._phase.to(obs.ENGINE_EMIT)
         for i, (slot, req) in enumerate(admitted):
             req.emit(int(first_h[i]))
             req.state = RequestState.DECODING
@@ -827,6 +847,7 @@ class InferenceEngine:
 
     # ---- prefix-cache hits: suffix-only bucketed prefill ---------- #
     def _admit_suffix(self, hits):
+        self._phase.to(obs.ENGINE_SCHEDULE)
         ecfg = self.ecfg
         pps = self.pool.pages_per_slot
         admitted: List[Tuple[int, Request]] = []
@@ -842,7 +863,7 @@ class InferenceEngine:
                 self.prefix_cache.unbind(req.request_id)
                 self.scheduler.requeue(req)
                 continue
-            req.state = RequestState.PREFILLING
+            self._note_admission(req)
             admitted.append((slot, req))
             matched_of[slot] = matched
         if not admitted:
@@ -883,6 +904,7 @@ class InferenceEngine:
             r_topp[i] = s.top_p if s.top_p < 1.0 else ecfg.top_p
             r_eos[i] = s.eos_id
             r_budget[i] = s.max_tokens - len(req.output)
+        self._phase.to(obs.PREFILL_DISPATCH)
         (self.cache, self.last_tok, self.pos, self.active, self.remaining,
          self.temps, self.top_ks, self.top_ps, self.eos_ids, self._key,
          self.spec_table, self.spec_prev,
@@ -900,12 +922,14 @@ class InferenceEngine:
                 2 * (self._view_bytes // self.ecfg.n_slots) * pad_n
         self.prefill_dispatch_tokens += pad_n * bucket
         self.suffix_prefills += len(admitted)
+        self._phase.to(obs.PREFILL_SYNC)
         first_h, done_h = jax.device_get((first, done0))
         self.host_syncs += 1
         self._post_admit(admitted, first_h, done_h)
 
     # ---- swap-parked resumes: zero re-prefill restore ------------- #
     def _admit_swapped(self, swaps: List[Request]):
+        self._phase.to(obs.ENGINE_SCHEDULE)
         paged, _ = split_paged(self.cache)
         restored: List[Tuple[int, Request]] = []
         for req in swaps:
@@ -954,6 +978,7 @@ class InferenceEngine:
             r_eos[i] = s.eos_id
             req.state = RequestState.DECODING
             self.slot_req[slot] = req
+        self._phase.to(obs.PREFILL_DISPATCH)
         (self.last_tok, self.pos, self.active, self.remaining, self.temps,
          self.top_ks, self.top_ps, self.eos_ids, self.spec_table,
          self.spec_prev) = self._restore_slots(
@@ -1038,11 +1063,15 @@ class InferenceEngine:
 
     # ---- decode: one fused K-step dispatch, one host sync --------- #
     def _decode_block(self) -> int:
+        self._phase.to(obs.DECODE_PAGES)
         self._ensure_decode_pages()
         if not self.slot_req:
             return 0
         mode = self._decode_mode()
         spec = self._spec_ok and mode == "greedy"
+        page_table = self.pool.page_table()
+        write_table = self.pool.write_table()
+        self._phase.to(obs.DECODE_DISPATCH)
         if spec:
             # one verify dispatch proposes + checks D drafts and emits
             # up to D+1 tokens — same single host sync as the fused path
@@ -1051,8 +1080,7 @@ class InferenceEngine:
              toks, emits, dones) = self._spec_decode(
                 self.params, self.cache, self.last_tok, self.pos,
                 self.active, self.remaining, self.eos_ids,
-                self.spec_table, self.spec_prev,
-                self.pool.page_table(), self.pool.write_table())
+                self.spec_table, self.spec_prev, page_table, write_table)
             self.spec_dispatches += 1
             self.logical_bytes_moved += \
                 (self.ecfg.spec_draft + 1) * self._write_token_bytes
@@ -1062,8 +1090,8 @@ class InferenceEngine:
              self.remaining, self._key, toks, emits, dones) = fn(
                 self.params, self.cache, self.last_tok, self.pos,
                 self.active, self.remaining, self.temps, self.top_ks,
-                self.top_ps, self.eos_ids, self._key,
-                self.pool.page_table(), self.pool.write_table())
+                self.top_ps, self.eos_ids, self._key, page_table,
+                write_table)
             if self._paged_attn:
                 # page-table-direct: only the block's new KV is written
                 self.logical_bytes_moved += \
@@ -1072,8 +1100,10 @@ class InferenceEngine:
                 # gather + scatter move every slot's full logical view
                 self.logical_bytes_moved += 2 * self._view_bytes
         self.dispatches += 1
+        self._phase.to(obs.DECODE_SYNC)
         toks_h, emit_h, done_h = jax.device_get((toks, emits, dones))
         self.host_syncs += 1
+        self._phase.to(obs.ENGINE_EMIT)
         emitted = 0
         for slot, req in list(self.slot_req.items()):
             col = emit_h[:, slot]

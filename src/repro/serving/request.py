@@ -55,6 +55,7 @@ class Request:
     state: RequestState = RequestState.QUEUED
     output: List[int] = dataclasses.field(default_factory=list)
     created_at: float = dataclasses.field(default_factory=time.monotonic)
+    admitted_at: Optional[float] = None       # first slot taken
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     error: str = ""

@@ -61,6 +61,32 @@ def test_refcount_leak_fixture_flagged():
                for v in vs), vs
 
 
+def test_calls_bind_to_their_module_before_same_named_functions(tmp_path):
+    """`obs.record()` binds to `repro/obs.py`'s `record`, and a bare
+    `record()` inside that module to its own; neither reaches another
+    class's `record`, whose `float()` would count as a hot-path sync."""
+    (tmp_path / "repro").mkdir()
+    (tmp_path / "repro" / "obs.py").write_text(
+        "def record(x):\n    return x\n\n\n"
+        "def span(x):\n    return record(x)\n")
+    (tmp_path / "perf.py").write_text(
+        "class PerfModel:\n    def record(self, x):\n"
+        "        return float(x)\n")
+    (tmp_path / "engine.py").write_text(
+        "from repro import obs\n\n\nclass InferenceEngine:\n"
+        "    def step(self):\n        obs.record(1)\n"
+        "        return obs.span(2)\n")
+    assert run_checkers([tmp_path], [HotPathSyncChecker()],
+                        root=tmp_path) == []
+    # the same call through an unknown receiver still reaches every
+    # `record`, as before
+    (tmp_path / "engine.py").write_text(
+        "class InferenceEngine:\n    def step(self):\n"
+        "        return self.stats.record(1)\n")
+    vs = run_checkers([tmp_path], [HotPathSyncChecker()], root=tmp_path)
+    assert [v.symbol for v in vs] == ["PerfModel.record"], vs
+
+
 def test_clean_fixture_passes_every_checker():
     checkers = [LockOrderChecker(), SharedStateChecker(),
                 HotPathSyncChecker(), MutableDefaultChecker(),

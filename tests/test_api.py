@@ -249,6 +249,35 @@ def test_admin_snapshot_typed_and_legacy_dict(live):
     assert dash["models"] == {m.name: m.replicas for m in snap.models}
 
 
+def test_admin_snapshot_reports_the_serving_loop_from_the_span_ring(
+        live, monkeypatch):
+    """Per node, the last minute of queue waits (p50/p95) and the
+    engine's host time per step net of device waits."""
+    from repro import obs
+    monkeypatch.setattr(obs, "RING", obs.Ring())
+    fleet, ctrl = live
+    gw = Gateway(ctrl)
+    for _ in range(3):
+        resp = gw.generate(MODEL, [1, 2, 3], SamplingParams(max_tokens=6))
+        assert len(resp.tokens) == 6
+    snap = gw.admin.snapshot()
+    served = [n for n in snap.nodes if n.host_ms_per_step is not None]
+    assert served
+    for n in served:
+        assert n.host_ms_per_step > 0
+        assert 0 <= n.queue_wait_p50_ms <= n.queue_wait_p95_ms
+    timing = snap.to_dict()["agents"][served[0].node_id]["timing"]
+    assert timing == {"queue_wait_p50_ms": served[0].queue_wait_p50_ms,
+                      "queue_wait_p95_ms": served[0].queue_wait_p95_ms,
+                      "host_ms_per_step": served[0].host_ms_per_step}
+    # a window the ring no longer holds reads as unknown, not as zero
+    monkeypatch.setattr(obs, "RING", obs.Ring(maxlen=1))
+    obs.record(obs.ENGINE_STEP, obs.clock(), obs.clock(), node="n0")
+    obs.record(obs.ENGINE_STEP, obs.clock(), obs.clock(), node="n0")
+    assert all(n.host_ms_per_step is None and n.queue_wait_p95_ms is None
+               for n in gw.admin.snapshot().nodes)
+
+
 def test_admin_scale_and_undeploy():
     model = "deepseek-r1-7b"
     fleet, ctrl = _accounted_stack(n_nodes=3, min_replicas=1)
