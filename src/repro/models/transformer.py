@@ -705,10 +705,37 @@ def prefill_suffix(params, cfg: ArchConfig, cache, tokens, offsets,
     return last, new_cache, pos
 
 
+def _scan_kv_carried(layer, h, cache, names, xs):
+    """Scan `layer((h, kv), xs) -> ((h, kv), ys)` over the stacked layers
+    with the stacked sequence-cache leaves `names` of `cache` in the carry
+    (`xs` gains the layer index under "layer").  Each layer writes its new
+    entries into the carried stacks and reads its own slice back, so the
+    stacks are updated in place; scanned in as `xs` and out as `ys` they
+    would be re-stacked into new arrays, a full copy of the cache every
+    call.  Returns (h, ys, new_cache)."""
+    kv = {n: cache[n] for n in names}
+    xs = dict(xs, layer=jnp.arange(cache[names[0]].shape[0]))
+    (h, kv), ys = jax.lax.scan(layer, (h, kv), xs)
+    return h, ys, {**cache, **kv}
+
+
+def _write_token(stack, layer, new, pos):
+    """Write each row's one new entry `new` (B, 1, ...) at (layer, row,
+    pos) of a stacked (L, B, S, ...) cache leaf.  `pos` clamps to S - 1
+    as `dynamic_update_slice` clamps a start: only a finished slot reaches
+    S, and its write then lands on its own last position."""
+    rows = jnp.arange(new.shape[0])
+    at = jnp.minimum(pos, stack.shape[2] - 1)
+    return stack.at[layer, rows, at].set(new[:, 0])
+
+
 def decode_step(params, cfg: ArchConfig, cache, token, pos, *,
                 sh: Sharder = _id_sh):
     """One decode step.  token: (B,) int32; pos: (B,) int32 — position of
     the *new* token (cache slots [0, pos) are valid; prefix included).
+    The stacked KV leaves (and their scales when quantized) ride in the
+    layer loop's carry and each layer writes its token into them in
+    place, so a step never copies the whole cache.
 
     Returns (logits (B, V), new_cache).
     """
@@ -720,11 +747,11 @@ def decode_step(params, cfg: ArchConfig, cache, token, pos, *,
     flags = _is_global_flags(cfg) if cfg.block == "hymba" else None
     prefix = cfg.n_meta_tokens + cfg.n_prefix_tokens
     quant = "k_scale" in cache
+    names = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
 
     def layer(carry, xs):
-        h = carry
-        lp = xs["lp"]
-        kc, vc = xs["k"], xs["v"]
+        h, kv = carry
+        lp, i = xs["lp"], xs["layer"]
         hs = xs.get("ssm")
         is_glob = xs.get("flag", cfg.swa_window == 0)
         if isinstance(is_glob, bool):
@@ -739,26 +766,18 @@ def decode_step(params, cfg: ArchConfig, cache, token, pos, *,
                                   cfg.rope_theta)
         q = L.apply_rope(q, cos, sin)
         k_new = L.apply_rope(k_new, cos, sin)
-        # write new kv at slot pos (per batch row)
-        upd = jax.vmap(
-            lambda c, n, p: jax.lax.dynamic_update_slice_in_dim(
-                c, n, p, axis=0))
-        ys = {}
         if quant:
-            kq, ks_new = kv_quantize(k_new)
-            vq, vs_new = kv_quantize(v_new)
-            kc = upd(kc, kq, pos)
-            vc = upd(vc, vq, pos)
-            ks = upd(xs["ks"], ks_new, pos)
-            vs = upd(xs["vs"], vs_new, pos)
-            ys.update(ks=ks, vs=vs)
-            k_at = kv_dequant(kc, ks)
-            v_at = kv_dequant(vc, vs)
+            (kq, ks), (vq, vs) = kv_quantize(k_new), kv_quantize(v_new)
+            new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
         else:
-            kc = upd(kc, k_new, pos)
-            vc = upd(vc, v_new, pos)
-            k_at, v_at = kc, vc
-        ys.update(k=kc, v=vc)
+            new = {"k": k_new, "v": v_new}
+        # write new kv at slot pos (per batch row)
+        kv = {n: _write_token(kv[n], i, new[n], pos) for n in names}
+        k_at, v_at = kv["k"][i], kv["v"][i]
+        if quant:
+            k_at = kv_dequant(k_at, kv["k_scale"][i])
+            v_at = kv_dequant(v_at, kv["v_scale"][i])
+        ys = {}
         a_out = attn_lib.decode_attention(q, k_at, v_at, pos,
                                           window=window, prefix=prefix)
         if cfg.block == "hymba":
@@ -785,21 +804,15 @@ def decode_step(params, cfg: ArchConfig, cache, token, pos, *,
         x = L.norm(h, lp.get("ln2"), cfg.norm)
         f_out, _ = _ffn(lp, cfg, x, sh)
         h = h + f_out
-        return h, ys
+        return (h, kv), ys
 
-    xs = {"lp": params["layers"], "k": cache["k"], "v": cache["v"]}
-    if quant:
-        xs["ks"], xs["vs"] = cache["k_scale"], cache["v_scale"]
+    xs = {"lp": params["layers"]}
     if flags is not None:
         xs["ssm"] = cache["ssm_h"]
         xs["flag"] = flags
     if cfg.is_encdec:
         xs["ck"], xs["cv"] = cache["ck"], cache["cv"]
-    h, ys = jax.lax.scan(layer, h, xs)
-    new_cache = dict(cache)
-    new_cache["k"], new_cache["v"] = ys["k"], ys["v"]
-    if quant:
-        new_cache["k_scale"], new_cache["v_scale"] = ys["ks"], ys["vs"]
+    h, ys, new_cache = _scan_kv_carried(layer, h, cache, names, xs)
     if cfg.block == "hymba":
         new_cache["ssm_h"] = ys["ssm"]
     h = L.norm(h, params.get("final_norm"), cfg.norm)
@@ -808,20 +821,21 @@ def decode_step(params, cfg: ArchConfig, cache, token, pos, *,
     return logits, new_cache
 
 
-def _paged_write(cache_pool, new_kv, write_table, w_pos):
-    """Scatter per-row KV into the physical page pool through the write
-    table.  cache_pool: (P, ps, K, hd); new_kv: (B, ..., K, hd) matching
-    w_pos (B, ...) absolute positions.  Unmapped / cache-shared /
-    out-of-range positions resolve to the sentinel and drop on device —
-    the paged analogue of `prefill_suffix`'s mode="drop" scatter."""
-    n_pages, ps = cache_pool.shape[0], cache_pool.shape[1]
+def _paged_write(pool, layer, new_kv, write_table, w_pos):
+    """Scatter per-row KV into layer `layer` of the stacked physical page
+    pool through the write table.  pool: (L, P, ps, K, hd); new_kv:
+    (B, ..., K, hd) matching w_pos (B, ...) absolute positions.
+    Unmapped / cache-shared / out-of-range positions resolve to the
+    sentinel and drop on device — the paged analogue of
+    `prefill_suffix`'s mode="drop" scatter."""
+    n_pages, ps = pool.shape[1], pool.shape[2]
     pps = write_table.shape[1]
     slot_page = w_pos // ps                                  # (B, Q)
     pid = jnp.take_along_axis(write_table,
                               jnp.minimum(slot_page, pps - 1), axis=1)
     pid = jnp.where(slot_page < pps, pid, n_pages)
-    return cache_pool.at[pid, w_pos % ps].set(
-        new_kv.astype(cache_pool.dtype), mode="drop")
+    return pool.at[layer, pid, w_pos % ps].set(
+        new_kv.astype(pool.dtype), mode="drop")
 
 
 def decode_step_paged(params, cfg: ArchConfig, cache, token, pos,
@@ -831,7 +845,9 @@ def decode_step_paged(params, cfg: ArchConfig, cache, token, pos,
     page_table/write_table: (B, pps) int32 with sentinel == n_pages.
     Paged leaves are the flat (L, n_pages, page_size, K, hd) pools;
     constant-size leaves (ssm states, enc-dec cross KV) stay
-    slot-resident.  Returns (logits (B, V), new_cache).
+    slot-resident.  The pools ride in the layer loop's carry and each
+    layer writes its token into them in place, so a step never copies a
+    whole pool.  Returns (logits (B, V), new_cache).
 
     Same family coverage as the paged engine (everything but xlstm);
     quantized KV keeps the gather path — per-page scale layout isn't
@@ -848,9 +864,8 @@ def decode_step_paged(params, cfg: ArchConfig, cache, token, pos,
     nkv = cfg.n_kv_heads
 
     def layer(carry, xs):
-        h = carry
-        lp = xs["lp"]
-        kc, vc = xs["k"], xs["v"]                # (P, ps, K, hd) pools
+        h, kv = carry
+        lp, i = xs["lp"], xs["layer"]
         hs = xs.get("ssm")
         is_glob = xs.get("flag", cfg.swa_window == 0)
         if isinstance(is_glob, bool):
@@ -865,12 +880,15 @@ def decode_step_paged(params, cfg: ArchConfig, cache, token, pos,
                                   cfg.rope_theta)
         q = L.apply_rope(q, cos, sin)
         k_new = L.apply_rope(k_new, cos, sin)
-        kc = _paged_write(kc, k_new, write_table, pos[:, None])
-        vc = _paged_write(vc, v_new, write_table, pos[:, None])
-        ys = {"k": kc, "v": vc}
+        kv = {"k": _paged_write(kv["k"], i, k_new, write_table,
+                                pos[:, None]),
+              "v": _paged_write(kv["v"], i, v_new, write_table,
+                                pos[:, None])}
+        ys = {}
         qf = q[:, 0].reshape(b, nkv, q.shape[2] // nkv, cfg.head_dim)
         a_out = kernel_ops.paged_decode_attention(
-            qf, kc, vc, page_table, pos, window=window, prefix=prefix)
+            qf, kv["k"][i], kv["v"][i], page_table, pos, window=window,
+            prefix=prefix)
         a_out = a_out.reshape(b, 1, q.shape[2], cfg.head_dim)
         if cfg.block == "hymba":
             inner = ssm_inner(cfg)
@@ -896,17 +914,15 @@ def decode_step_paged(params, cfg: ArchConfig, cache, token, pos,
         x = L.norm(h, lp.get("ln2"), cfg.norm)
         f_out, _ = _ffn(lp, cfg, x, sh)
         h = h + f_out
-        return h, ys
+        return (h, kv), ys
 
-    xs = {"lp": params["layers"], "k": cache["k"], "v": cache["v"]}
+    xs = {"lp": params["layers"]}
     if flags is not None:
         xs["ssm"] = cache["ssm_h"]
         xs["flag"] = flags
     if cfg.is_encdec:
         xs["ck"], xs["cv"] = cache["ck"], cache["cv"]
-    h, ys = jax.lax.scan(layer, h, xs)
-    new_cache = dict(cache)
-    new_cache["k"], new_cache["v"] = ys["k"], ys["v"]
+    h, ys, new_cache = _scan_kv_carried(layer, h, cache, ("k", "v"), xs)
     if cfg.block == "hymba":
         new_cache["ssm_h"] = ys["ssm"]
     h = L.norm(h, params.get("final_norm"), cfg.norm)
@@ -925,7 +941,9 @@ def spec_verify_paged(params, cfg: ArchConfig, cache, tokens, pos,
     position of tokens[:, 0].  KV for *every* fed position is written
     through the write table (rejected drafts leave garbage beyond the
     accepted position — masked by causality and overwritten when decode
-    resumes there).  Returns (logits (B, Q, V), new_cache).
+    resumes there).  The pools ride in the layer loop's carry, written in
+    place as in `decode_step_paged`, so a verify never copies a whole
+    pool.  Returns (logits (B, Q, V), new_cache).
 
     Plain causal decoders only (recurrent state can't roll back a
     rejected draft; windows/prefix/cross-KV change visibility) — the
@@ -941,9 +959,8 @@ def spec_verify_paged(params, cfg: ArchConfig, cache, tokens, pos,
     q_pos = pos[:, None] + jnp.arange(qn)[None, :]             # (B,Q)
 
     def layer(carry, xs):
-        h = carry
-        lp = xs["lp"]
-        kc, vc = xs["k"], xs["v"]
+        h, kv = carry
+        lp, i = xs["lp"], xs["layer"]
         x = L.norm(h, lp.get("ln1"), cfg.norm)
         q = jnp.einsum("bsd,dhk->bshk", x, lp["attn"]["wq"])
         k_new = jnp.einsum("bsd,dhk->bshk", x, lp["attn"]["wk"])
@@ -951,20 +968,18 @@ def spec_verify_paged(params, cfg: ArchConfig, cache, tokens, pos,
         cos, sin = L.rope_cos_sin(q_pos, cfg.head_dim, cfg.rope_theta)
         q = L.apply_rope(q, cos, sin)
         k_new = L.apply_rope(k_new, cos, sin)
-        kc = _paged_write(kc, k_new, write_table, q_pos)
-        vc = _paged_write(vc, v_new, write_table, q_pos)
-        a_out = kernel_ops.paged_suffix_attention(q, kc, vc,
-                                                  page_table, pos)
+        kv = {"k": _paged_write(kv["k"], i, k_new, write_table, q_pos),
+              "v": _paged_write(kv["v"], i, v_new, write_table, q_pos)}
+        a_out = kernel_ops.paged_suffix_attention(
+            q, kv["k"][i], kv["v"][i], page_table, pos)
         h = h + jnp.einsum("bshk,hkd->bsd", a_out, lp["attn"]["wo"])
         x = L.norm(h, lp.get("ln2"), cfg.norm)
         f_out, _ = _ffn(lp, cfg, x, sh)
         h = h + f_out
-        return h, {"k": kc, "v": vc}
+        return (h, kv), {}
 
-    xs = {"lp": params["layers"], "k": cache["k"], "v": cache["v"]}
-    h, ys = jax.lax.scan(layer, h, xs)
-    new_cache = dict(cache)
-    new_cache["k"], new_cache["v"] = ys["k"], ys["v"]
+    h, _, new_cache = _scan_kv_carried(layer, h, cache, ("k", "v"),
+                                       {"lp": params["layers"]})
     h = L.norm(h, params.get("final_norm"), cfg.norm)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", h, head)
