@@ -2,13 +2,14 @@
 
 Once the window has closed and the program's state is freed, a sample
 of the finished requests, drawn from the seed and always holding the
-longest, is run through the plain float32 reference over prompt + served
-tokens.  The number compared is the widest gap by which a served token's
-reference logit lies below the reference's best at its position: a
-greedy token from a sound bf16 path is the argmax or a near-tie; one
-from a broken path lies anywhere in the vocabulary.  Every request of
-the window must also have come back whole: an answer that never comes
-fails the run as a wrong one does.
+longest, is run through the configuration's family's plain float32
+reference over prompt + served tokens.  The number compared is the
+widest gap by which a served token's reference logit lies below the
+reference's best at its position: a greedy token from a sound bf16
+path is the argmax or a near-tie; one from a broken path lies anywhere
+in the vocabulary.  Every request of the window must also have come
+back whole: an answer that never comes fails the run as a wrong one
+does.
 
 The control is the reference computed in fp8 and put in the program's
 place: at each position of the same prompts and served tokens, the
@@ -64,10 +65,12 @@ def pad_len(max_len: int) -> int:
     return -(-max_len // q) * q
 
 
-def judge(window_records: Sequence, sample: Sequence, weights: Dict,
-          arch: Dict, cfg: Dict, *, control: bool = False) -> Verdict:
-    """Compare the sample with the reference; count what never came.
-    With `control`, judge the fp8 control's tokens the same way."""
+def judge(window_records: Sequence, sample: Sequence, family,
+          weights: Dict, arch: Dict, cfg: Dict, *,
+          control: bool = False) -> Verdict:
+    """Compare the sample with `family`'s reference; count what never
+    came.  With `control`, judge the fp8 control's tokens the same
+    way."""
     limit = cfg["limits"]["widest_logit_gap"]
     # a configuration whose limit is not yet set judges nothing correct
     limit = float("nan") if limit is None else float(limit)
@@ -75,8 +78,9 @@ def judge(window_records: Sequence, sample: Sequence, weights: Dict,
     pad = pad_len(int(cfg["engine"]["max_len"]))
     widest = ctrl = 0.0 if sample else float("inf")
     for r in sample:
-        g, c = reference.gaps(weights, arch, r.spec.prompt.tolist(),
-                              r.tokens, pad, control=control)
+        g, c = reference.gaps(family, weights, arch,
+                              r.spec.prompt.tolist(), r.tokens, pad,
+                              control=control)
         widest = max(widest, float(np.max(g)))
         if control:
             ctrl = max(ctrl, float(np.max(c)))

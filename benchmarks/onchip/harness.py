@@ -8,9 +8,10 @@ committed to its device, the deploy defaults), and every request goes
 Gateway -> frontend -> node -> engine from the client side.  The
 benchmark makes the weights (`weights.py`), the traffic (`traffic.py`),
 the timings (`loadgen.py`), the reduction of the trace (`tracing.py`),
-the work counts (`work.py`), the reference (`reference.py`) and the
-verdict (`correctness.py`); from the program it takes only the stack,
-its counters and its program names.
+the verdict (`correctness.py`), and through the configuration's model
+family (`families/<family>.py`) the program's configuration, the
+weights' scales, the reference and the work counts; from the program it
+takes only the stack, its counters and its program names.
 """
 from __future__ import annotations
 
@@ -163,16 +164,11 @@ class GcPauses:
 # ------------------------------------------------------------------ #
 # the system under test
 # ------------------------------------------------------------------ #
-def build_arch(cfg: Dict):
-    from repro.configs.base import ArchConfig
-    return ArchConfig(name=cfg["name"], family="dense", **cfg["arch"])
-
-
-def make_weights(arch, seed: int, device):
+def make_weights(family, arch, seed: int, device):
     import jax
     from repro.models import build
     shapes = jax.eval_shape(build(arch).init, jax.random.PRNGKey(0))
-    return weights_lib.make(shapes, seed, device)
+    return weights_lib.make(shapes, seed, device, family.leaf_std)
 
 
 @dataclasses.dataclass
@@ -311,6 +307,7 @@ def peak_bytes(devices) -> int:
 @dataclasses.dataclass
 class Context:
     """What the metric readers read."""
+    family: object           # the config's `families/<family>.py`
     arch: Dict
     engine: Dict
     peaks: Dict
@@ -358,6 +355,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     bench = bench or spec_lib.load_benchmark()
     cell = bench.cell(workload)
     cfg = spec_lib.load_config(cell.config, base)
+    family = spec_lib.load_family(cfg["family"], base)
     mix = dict(spec_lib.load_traffic(cell.traffic, base), **(mix_over or {}))
     if check_device:
         device = require_chips(cell.chips)
@@ -368,11 +366,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                   "count": cell.chips}
         peaks = {}
     devices = jax.devices()[:cell.chips]
-    arch = build_arch(cfg)
+    arch = family.build_arch(cfg)
     clog = CompileLog()
     stack = None
     try:
-        params = make_weights(arch, seed, devices[0])
+        params = make_weights(family, arch, seed, devices[0])
         stack = deploy(arch, cfg, params, devices)
         warmed = warm(stack, mix, arch, clog)
         traces_before = program_traces(stack)
@@ -434,9 +432,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         sample = correctness.pick_sample(
             judged, seed, int(target["sample_tokens"]),
             int(target["max_requests"]))
-        verdict = correctness.judge(judged, sample, params, cfg["arch"],
-                                    cfg, control=control)
-        ctx = Context(cfg["arch"], cfg["engine"], peaks, window,
+        verdict = correctness.judge(judged, sample, family, params,
+                                    cfg["arch"], cfg, control=control)
+        ctx = Context(family, cfg["arch"], cfg["engine"], peaks, window,
                       window.t0 - started,
                       [n.node_id for n, _ in stack.engines], summary)
         names = [m.name for m in (bench.per_layer_of(workload) if trace

@@ -4,13 +4,14 @@ where the run gave it nothing to read, never 0 for a share of a peak.
 
 Tails are nearest-rank percentiles over every sample: a request that
 failed counts as infinitely late in each latency tail it belongs to.
+Work counts are the configuration's family's (`ctx.family`).
 """
 from __future__ import annotations
 
 import math
 from typing import List, Optional, Sequence
 
-import work
+import spans
 
 INF = float("inf")
 
@@ -98,6 +99,22 @@ def prefill_prompts(ctx) -> Optional[List[int]]:
             if r.times and tw[0] <= r.times[0] <= tw[1]]
 
 
+class Counters:
+    """The window's program counters: `get(name)` gives the program's
+    records of `name` (`repro.obs`) that ended in the traced sub-window,
+    on the window's clock, or None where the program keeps none or the
+    ring lost part of it.  A family whose work depends on what the
+    program chose (the experts its tokens were routed to) counts from
+    them; a plain dict stands in for them in a test."""
+
+    def __init__(self, ctx):
+        self._ctx = ctx
+
+    def get(self, name: str) -> Optional[List]:
+        tw = _traced(self._ctx)
+        return None if tw is None else spans.in_window(self._ctx, name, tw)
+
+
 def program_time(ctx, fragment: str):
     """(device seconds, runs) of the program, or None untraced/absent."""
     if _traced(ctx) is None:
@@ -127,11 +144,11 @@ def decode_roofline(ctx) -> Optional[float]:
     got, contexts = program_time(ctx, DECODE), decode_contexts(ctx)
     if got is None or not contexts:
         return None
-    a, p = ctx.arch, ctx.peaks
+    a, p, f, n = ctx.arch, ctx.peaks, ctx.family, Counters(ctx)
     steps = got[1] * ctx.engine["decode_block"]
-    least = max(work.decode_bytes(a, steps, contexts)
+    least = max(f.decode_bytes(a, steps, contexts, n)
                 / p["hbm_bytes_per_s"],
-                work.decode_flops(a, contexts) / p["bf16_flops_per_s"])
+                f.decode_flops(a, contexts, n) / p["bf16_flops_per_s"])
     return least / got[0] * 100.0
 
 
@@ -139,7 +156,7 @@ def mfu_decode(ctx) -> Optional[float]:
     got, contexts = program_time(ctx, DECODE), decode_contexts(ctx)
     if got is None or not contexts:
         return None
-    flops = work.decode_flops(ctx.arch, contexts)
+    flops = ctx.family.decode_flops(ctx.arch, contexts, Counters(ctx))
     return flops / got[0] / ctx.peaks["bf16_flops_per_s"] * 100.0
 
 
@@ -147,7 +164,7 @@ def mfu_prefill(ctx) -> Optional[float]:
     got, prompts = program_time(ctx, PREFILL), prefill_prompts(ctx)
     if got is None or not prompts:
         return None
-    flops = work.prefill_flops(ctx.arch, prompts)
+    flops = ctx.family.prefill_flops(ctx.arch, prompts, Counters(ctx))
     return flops / got[0] / ctx.peaks["bf16_flops_per_s"] * 100.0
 
 

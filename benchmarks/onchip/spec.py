@@ -5,16 +5,20 @@ configuration, traffic mix and chips, and each metric.  Everything that
 belongs to one configuration, one traffic mix or one per-layer metric
 lives in a file of its own under this directory, found by that name:
 
-    configs/<config>.json     sizes, engine settings, limits, source
+    configs/<config>.json     sizes, engine settings, limits, source,
+                              and the family it names
+    families/<family>.py      what is particular to a model family:
+                              builder, weight scales, reference, work
     traffic/<traffic>.json    parameters of the one general generator
     metrics/<metric>.py       a reader: UNIT and read(ctx) -> float|None
 
-so a later change adds a cell, a mix or a metric by adding files and
-entries, and edits nothing that is here.
+so a later change adds a cell, a mix, a metric or a model family by
+adding files and entries, and edits nothing that is here.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
@@ -94,11 +98,13 @@ def _json_file(kind: str, name: str, base: pathlib.Path) -> Dict[str, Any]:
 
 
 def load_config(name: str, base: pathlib.Path = HERE) -> Dict[str, Any]:
-    """configs/<name>.json: `arch` (the model's sizes as run), `engine`
-    (deployment settings), `limits` (what `correct` compares), `check`
-    (the sample it compares), `source`, `reduced`, `assumed`."""
+    """configs/<name>.json: `family` (the `families/<family>.py` that
+    reads it), `arch` (the model's sizes as run), `engine` (deployment
+    settings), `limits` (what `correct` compares), `check` (the sample
+    it compares), `source`, `reduced`, `assumed`."""
     data = _json_file("configs", name, base)
-    for key in ("arch", "engine", "limits", "check", "source", "reduced"):
+    for key in ("family", "arch", "engine", "limits", "check", "source",
+                "reduced"):
         if key not in data:
             raise SpecError(f"config {name!r} has no {key!r}")
     return data
@@ -110,6 +116,46 @@ def load_traffic(name: str, base: pathlib.Path = HERE) -> Dict[str, Any]:
     if data.get("loop") not in ("open", "closed"):
         raise SpecError(f"traffic {name!r}: loop must be open or closed")
     return data
+
+
+# what a family module provides; `a` is a config's `arch`, `counters`
+# the window's program counters (`readers.Counters`: `get(name)`)
+FAMILY = (
+    "build_arch",       # (cfg) -> the program's ArchConfig
+    "leaf_std",         # (path, shape) -> the scale of one weight leaf
+    "logits",           # (weights, arch, tokens, pad_to, precision, rows)
+                        #   -> the plain float32 reference's logits
+    "decode_flops",     # (a, contexts, counters) -> the algorithm's work
+    "decode_bytes",     # (a, steps, contexts, counters)
+    "prefill_flops",    # (a, prompt_lens, counters)
+)
+
+
+def load_family(name: str, base: pathlib.Path = HERE):
+    """families/<name>.py, loaded by path, once a process for each file
+    (its jitted reference keeps its compiled programs)."""
+    path = base / "families" / f"{name}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in (base / "families").glob("*.py"))
+        raise SpecError(f"no family {name!r} ({path}); there are {known}")
+    mod = _family_module(name, path.resolve())
+    missing = [f for f in FAMILY if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SpecError(f"family {name!r} ({path}) lacks {missing}")
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _family_module(name: str, path: pathlib.Path):
+    return _exec_module("onchip_family_", name, path)
+
+
+def _exec_module(prefix: str, name: str, path: pathlib.Path):
+    mod_name = prefix + "".join(c if c.isalnum() else "_" for c in name)
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,9 +170,5 @@ def load_reader(name: str, base: pathlib.Path = HERE) -> Reader:
     path = base / "metrics" / f"{name}.py"
     if not path.is_file():
         raise SpecError(f"no reader for metric {name!r} ({path})")
-    mod_name = "onchip_metric_" + "".join(
-        c if c.isalnum() else "_" for c in name)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = _exec_module("onchip_metric_", name, path)
     return Reader(name, mod.UNIT, mod.read)

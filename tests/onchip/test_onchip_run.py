@@ -16,7 +16,7 @@ import spec
 
 from repro.serving.request import Request
 
-TINY = {"name": "tiny", "source": "test", "reduced": [],
+TINY = {"name": "tiny", "family": "dense", "source": "test", "reduced": [],
         "arch": {"n_layers": 2, "d_model": 64, "n_heads": 4,
                  "n_kv_heads": 2, "head_dim": 16, "d_ff": 128, "vocab": 256,
                  "norm": "rms", "act": "swiglu", "tie_embeddings": True,
@@ -40,7 +40,8 @@ def bench(tmp_path_factory):
     base = tmp_path_factory.mktemp("bench")
     for d in ("configs", "traffic"):
         (base / d).mkdir()
-    shutil.copytree(spec.HERE / "metrics", base / "metrics")
+    for d in ("families", "metrics"):
+        shutil.copytree(spec.HERE / d, base / d)
     (base / "configs" / "tiny.json").write_text(json.dumps(TINY))
     for mix in (CHAT, DOC):
         (base / "traffic" / f"{mix['name']}.json").write_text(
